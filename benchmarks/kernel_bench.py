@@ -103,7 +103,7 @@ def _time_backend_round(backend, plan, x):
     actual per-round unit (packed words for 𝔹 on the host)."""
     if backend == "pallas":
         return timeit(lambda: coo_spmm.spmm_pallas(
-            plan, x, interpret=jax.default_backend() != "tpu"), iters=3)
+            plan, x, interpret=ops.pallas_interpret()), iters=3)
     if plan.sr_name == "bool":
         words = coo_spmm.pack_lanes(np.asarray(x).T)
         return timeit(lambda: coo_spmm.bool_round_packed(plan, words),
@@ -114,8 +114,8 @@ def _time_backend_round(backend, plan, x):
 
 def _interpret_parity(sr_name: str, seed: int, n: int = 384,
                       b: int = 8) -> bool:
-    """Small interpret-mode Pallas cell vs the jnp oracle, so the kernel
-    path compiles-and-matches even on a CPU bench host."""
+    """Small Pallas cell vs the jnp oracle, so the kernel path matches
+    even on a CPU bench host (interpreted there, compiled on TPU)."""
     g = _graph(n, 3, seed)
     rel = g.sparse_adjacency(
         semiring=sr_name if sr_name in ("bool", "trop", "maxplus")
@@ -128,7 +128,8 @@ def _interpret_parity(sr_name: str, seed: int, n: int = 384,
                                       rel.shape, sr_name)
     x = jnp.asarray(_frontier(n, b, sr_name, seed + 7))
     plan = coo_spmm.plan_geometry(rel, transpose=True)
-    got = np.asarray(coo_spmm.spmm_pallas(plan, x, interpret=True))
+    got = np.asarray(coo_spmm.spmm_pallas(plan, x,
+                                          interpret=ops.pallas_interpret()))
     want = np.asarray(contract.spmm(rel, x, transpose=True))
     return np.array_equal(got, want)
 
@@ -151,11 +152,9 @@ def run_spmm(n=50_000, batches=(1, 8, 64), avg_degs=(4, 16),
                                               sr_name)
             rel_j = rel.as_jnp()
             plan = coo_spmm.plan_geometry(rel_j, transpose=True)
-            # the *hardware* backend, never interpret mode: under
-            # REPRO_PALLAS_INTERPRET (the CI flag) spmm_exec_backend
-            # resolves "pallas", but timing the interpreter would make
-            # every speedup a fiction — interpret parity is the
-            # separate cells below
+            # the *hardware* backend, never interpret mode: timing the
+            # interpreter would make every speedup a fiction —
+            # interpret parity is the separate cells below
             backend = ("pallas" if jax.default_backend() == "tpu"
                        else "fused")
             for b in batches:
@@ -172,8 +171,7 @@ def run_spmm(n=50_000, batches=(1, 8, 64), avg_degs=(4, 16),
                         coo_spmm.bool_round_packed(plan, words), b).T
                 elif backend == "pallas":
                     got = np.asarray(coo_spmm.spmm_pallas(
-                        plan, xj,
-                        interpret=jax.default_backend() != "tpu"))
+                        plan, xj, interpret=ops.pallas_interpret()))
                 else:
                     got = coo_spmm.spmm_host(plan, x)
                 assert np.array_equal(np.asarray(got), want), \

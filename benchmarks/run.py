@@ -105,6 +105,8 @@ def run_suite(name: str, overrides: dict | None = None,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=",".join(SUITES),
                     help=f"comma-separated subset of {sorted(SUITES)}")

@@ -313,6 +313,15 @@ class SpmmKernelModel:
 SPMM_COST = SpmmKernelModel()
 
 
+def _device_bytes() -> float | None:
+    """Memory of the default device where the backend reports it (TPU);
+    ``None`` elsewhere — the host's memory is not the planner's to
+    budget."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return float(limit) if limit else None
+
+
 def spmm_exec_backend(runner: str = "sparse_frontier_pallas") -> str:
     """Resolve a runner's SpMM execution backend on this host.
 
@@ -325,7 +334,7 @@ def spmm_exec_backend(runner: str = "sparse_frontier_pallas") -> str:
     if runner != "sparse_frontier_pallas":
         return "jnp"
     from repro.kernels import ops as kops
-    if jax.default_backend() == "tpu" or kops._FORCE_INTERPRET:
+    if kops._use_pallas():
         return "pallas"
     return "fused"
 
@@ -633,11 +642,13 @@ def _plan_stratum(prog, stratum, si, db, hints, *, objective, forced,
                 rejected[r] = why
             vf = None
     e_nnz = None
+    e_rel = None   # the sparse operator itself, when one is stored
     n_vec = n_dom
     if vf is not None:
         n_vec = db.dom(vf.out_sort)
         if edges is not None:
             if isinstance(edges, SparseRelation):
+                e_rel = edges
                 e_nnz = float(np.asarray(edges.as_np().nnz))
             # a dense override keeps the vector_dense candidate below
         else:
@@ -646,6 +657,7 @@ def _plan_stratum(prog, stratum, si, db, hints, *, objective, forced,
                     and planned.get(ename) == "sparse"):
                 arr = db.relations[ename]
                 if isinstance(arr, SparseRelation):
+                    e_rel = arr
                     e_nnz = float(np.asarray(arr.as_np().nnz))
                 else:
                     e_nnz = densities[ename] * float(
@@ -802,9 +814,29 @@ def _plan_stratum(prog, stratum, si, db, hints, *, objective, forced,
                     f"minimum (BENCH_kernels.json) — geometry planning "
                     f"outweighs the per-iteration win")
             else:
-                considered["sparse_frontier_pallas"] = CostEstimate(
-                    (e_nnz + n_vec) / sp_up + n_vec,
-                    (12.0 * e_nnz + 4.0 * n_vec) / sp_up, trips)
+                # the Pallas kernel sweeps padded edge tiles, not edges:
+                # price its real slot count (the host executors sweep
+                # the bare dst-sorted edges)
+                slots = e_nnz
+                if e_rel is not None and spmm_exec_backend() == "pallas":
+                    from repro.kernels import coo_spmm
+                    slots = float(coo_spmm.padded_slots(e_rel))
+                hbm = _device_bytes()
+                if hbm is not None and 12.0 * slots > hbm:
+                    rejected["sparse_frontier_pallas"] = (
+                        f"padded edge-tile geometry does not fit: "
+                        f"{int(slots)} slots ({slots / e_nnz:.1f}/edge) × "
+                        f"12 B > {hbm / 2**30:.1f} GiB of device memory")
+                elif sp_up * e_nnz / slots <= 1.0:
+                    rejected["sparse_frontier_pallas"] = (
+                        f"padded edge-tile geometry: {int(slots)} slots "
+                        f"for nnz(E)={int(e_nnz)} "
+                        f"({slots / e_nnz:.1f}/edge) outweigh the "
+                        f"kernel's {sp_up:g}× per-slot win")
+                else:
+                    considered["sparse_frontier_pallas"] = CostEstimate(
+                        (slots + n_vec) / sp_up + n_vec,
+                        (12.0 * slots + 4.0 * n_vec) / sp_up, trips)
 
     # the host worklist only pays off for single-shot latency on a CPU
     # host; batched serving and accelerators want the staged SpMM loop

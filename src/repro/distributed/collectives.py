@@ -39,7 +39,7 @@ def compressed_grad_reduce(grads, mesh, axis_name: str = "pod",
                            mode: str = "bf16"):
     """Reduce a grad pytree over ``axis_name`` with wire compression."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     red = bf16_all_reduce if mode == "bf16" else int8_all_reduce
 
@@ -49,4 +49,4 @@ def compressed_grad_reduce(grads, mesh, axis_name: str = "pod",
 
     spec = jax.tree.map(lambda _: P(), grads)
     return shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_rep=False)(grads)
+                     check_vma=False)(grads)

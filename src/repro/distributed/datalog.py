@@ -64,15 +64,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import semiring as sr_mod
 from repro.sparse.coo import SparseRelation
-
-try:  # jax ≥ 0.4.35 exposes shard_map at the top level eventually
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax import shard_map  # type: ignore[attr-defined]
 
 #: the mesh axis name every sharded fixpoint runs over
 GRAPH_AXIS = "graph"
@@ -701,7 +697,7 @@ def sharded_contract(edges, x, *, mesh: Mesh):
 
     out = shard_map(body, mesh=mesh,
                     in_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS), vspec),
-                    out_specs=vspec, check_rep=False)(
+                    out_specs=vspec, check_vma=False)(
         es.coords, es.values, xv)
     out = jnp.take(out, es.perm, axis=0) if es.perm is not None \
         else out[:n]
@@ -875,7 +871,7 @@ def _dispatch(edges, mesh, *, init=None, warm=None, max_iters=10_000,
         in_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS))
         + (P(GRAPH_AXIS),) * len(geo_in) + wspecs,
         out_specs=out_specs,
-        check_rep=False)(
+        check_vma=False)(
         es.coords, es.values, *geo_in, *carry_in)
     y = jnp.take(y, es.perm, axis=0) if es.perm is not None else y[:n]
     if chunk:

@@ -20,7 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -78,7 +78,7 @@ def run_pipeline(mesh: Mesh, stage_fn, stage_params, x_micro, *,
     param_spec = jax.tree.map(lambda _: P("stage"), stage_params)
     fn = shard_map(body, mesh=mesh,
                    in_specs=(param_spec, P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(stage_params, x_micro)
 
 

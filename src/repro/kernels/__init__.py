@@ -2,8 +2,8 @@
 
 Every kernel has a jnp/np oracle in ``ref.py`` and platform dispatch in
 ``ops.py`` (TPU → compiled Pallas, elsewhere → oracle, with
-``REPRO_PALLAS_INTERPRET`` / :func:`force_pallas_interpret` routing
-through the kernels in interpret mode for CI parity).
+:func:`force_pallas_interpret` routing a CPU host through the kernels in
+interpret mode for test parity; a TPU never interprets).
 
 * ``coo_spmm.py`` — fused batched COO semiring SpMM (DESIGN.md §9):
   gather → ⊗ → segment-⊕ in one pass over edge tiles.  The serving hot

@@ -55,7 +55,7 @@ def _kernel(blk_ref, first_ref, vals_ref, loc_ref, o_ref, *, mode: str,
     lanes = jax.lax.broadcasted_iota(jnp.int32, (bk, bn), 1)
     onehot = loc[:, None] == lanes                        # (bk, bn)
     masked = jnp.where(onehot, vals[:, None], init)
-    o_ref[0, :] = comb(o_ref[0, :], red(masked, axis=0))
+    o_ref[...] = comb(o_ref[...], red(masked, axis=0, keepdims=True))
 
 
 @functools.partial(jax.jit,
@@ -113,22 +113,23 @@ def segment_reduce_pallas(vals: jnp.ndarray, segment_ids: jnp.ndarray,
         v_s, mode="drop")
     buf_l = jnp.zeros((cap_e,), jnp.int32).at[slot].set(loc_s, mode="drop")
 
+    # one (1, bk) chunk row / (1, bn) output row per grid step: the
+    # unit middle axis makes the last two block dims equal the array's,
+    # as Mosaic requires of blocks that are not (8, 128)-aligned
+    row = pl.BlockSpec((None, 1, bk), lambda c, blk_r, first_r: (c, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(cap_chunks,),
-        in_specs=[
-            pl.BlockSpec((1, bk), lambda c, blk_r, first_r: (c, 0)),
-            pl.BlockSpec((1, bk), lambda c, blk_r, first_r: (c, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bn),
-                               lambda c, blk_r, first_r: (blk_r[c], 0)),
+        in_specs=[row, row],
+        out_specs=pl.BlockSpec((None, 1, bn),
+                               lambda c, blk_r, first_r: (blk_r[c], 0, 0)),
     )
     out = pl.pallas_call(
         functools.partial(_kernel, mode=sr_name, bk=bk, bn=bn),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nblocks, bn), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nblocks, 1, bn), jnp.float32),
         interpret=interpret,
-    )(blk_of_chunk, first, buf_v.reshape(cap_chunks, bk),
-      buf_l.reshape(cap_chunks, bk))
+    )(blk_of_chunk, first, buf_v.reshape(cap_chunks, 1, bk),
+      buf_l.reshape(cap_chunks, 1, bk))
     flat = out.reshape(-1)[:n]
     return flat > 0.5 if is_bool else flat

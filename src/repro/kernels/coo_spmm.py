@@ -58,7 +58,8 @@ _DOT = ("bool", "nat", "real")
 
 #: (bk edges/chunk, bs gather rows, bn output rows).  The dot family
 #: amortizes one-hot matmuls over big tiles; the select-reduce family
-#: materializes (bk, bs, B) masks so its tiles stay small.
+#: unrolls one select per gather row and per output row, so its tiles
+#: stay small.
 _BLOCKS = {"dot": (256, 256, 128), "minmax": (32, 32, 32)}
 
 
@@ -146,6 +147,25 @@ def _build_plan(rel, transpose: bool) -> SpmmPlan:
 # Pallas kernel
 
 
+def padded_slots(rel, *, transpose: bool = True) -> int:
+    """Edge slots of the Pallas chunk geometry, counted without building
+    it: every non-empty (out block, src block) bucket rounds up to whole
+    ``bk``-slot chunks and every empty out block takes one all-pad chunk
+    (:func:`_build_chunks`).  The kernel's per-round work and its
+    geometry's bytes both scale with this count, not with nnz."""
+    h = rel.as_np()
+    k = int(h.nnz)
+    ci, co = (0, 1) if transpose else (1, 0)
+    bk, bs, bn = _BLOCKS[_family(rel.semiring)]
+    nsb = max(1, -(-int(h.shape[ci]) // bs))
+    ndb = max(1, -(-int(h.shape[co]) // bn))
+    key = (np.asarray(h.coords[:k, co], np.int64) // bn) * nsb \
+        + np.asarray(h.coords[:k, ci], np.int64) // bs
+    ub, cnt = np.unique(key, return_counts=True)
+    empty = ndb - len(np.unique(ub // nsb))
+    return int((np.sum(-(-cnt // bk)) + empty) * bk)
+
+
 def _chunk_geometry(plan: SpmmPlan) -> tuple:
     if plan.chunks is None:
         plan.chunks = _build_chunks(plan)
@@ -192,18 +212,21 @@ def _build_chunks(plan: SpmmPlan) -> tuple:
     first[1:] = (dblk[1:] != dblk[:-1]).astype(np.int32)
     # pad slots: loc = block size ⇒ one-hot all-miss on both axes, value
     # = ⊕-identity — they contribute nothing on either kernel body
-    locs = np.full((c_total, bk), bs, np.int32)
-    locd = np.full((c_total, bk), bn, np.int32)
-    vbuf = np.full((c_total, bk), _PAD[plan.sr_name], np.float32)
+    # (c_total, 1, bk): one chunk row per grid step whose last two
+    # block dims equal the array's, as Mosaic requires of a block that
+    # is not (8, 128)-aligned
+    locs = np.full((c_total, 1, bk), bs, np.int32)
+    locd = np.full((c_total, 1, bk), bn, np.int32)
+    vbuf = np.full((c_total, 1, bk), _PAD[plan.sr_name], np.float32)
     if plan.nnz:
         b_of = np.searchsorted(bstart, np.arange(plan.nnz),
                                side="right") - 1
         pos = np.arange(plan.nnz) - bstart[b_of]
         chunk = cstart[erank[b_of]] + pos // bk
         slot = pos % bk
-        locs[chunk, slot] = (g_s % bs).astype(np.int32)
-        locd[chunk, slot] = (o_s % bn).astype(np.int32)
-        vbuf[chunk, slot] = v_s
+        locs[chunk, 0, slot] = (g_s % bs).astype(np.int32)
+        locd[chunk, 0, slot] = (o_s % bn).astype(np.int32)
+        vbuf[chunk, 0, slot] = v_s
     # plain numpy on purpose: geometry may be first materialized under an
     # outer trace (the per-operator jitted fixpoints), where jnp.asarray
     # would yield leakable tracers — as np buffers they enter jit as
@@ -242,16 +265,18 @@ def _spmm_kernel(sblk_ref, dblk_ref, first_ref, locs_ref, locd_ref,
     else:
         red, comb = (jnp.min, jnp.minimum) if mode == "trop" else \
             (jnp.max, jnp.maximum)
-        src_oh = locs[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (bk, bs), 1)                       # (bk, bs)
-        g = red(jnp.where(src_oh[:, :, None], x[None, :, :], init),
-                axis=1)                                   # (bk, bp)
+        # 2-D selects only: Mosaic has no shape cast for the (bk, bs, B)
+        # broadcast form.  Gather one source row at a time (each slot
+        # matches at most one row; pad slots match none and keep 0̄) …
+        g = jnp.full((bk, x.shape[1]), init, jnp.float32)
+        for s in range(bs):
+            g = jnp.where(locs[:, None] == s, x[s:s + 1, :], g)
         p = w[:, None] + g                                # ⊗ is +
-        dst_oh = locd[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (bk, bn), 1)                       # (bk, bn)
-        contrib = red(jnp.where(dst_oh[:, :, None], p[:, None, :], init),
-                      axis=0)                             # (bn, bp)
-        o_ref[...] = comb(o_ref[...], contrib)
+        # … then ⊕-reduce the slots landing on each output row
+        for r in range(bn):
+            row = red(jnp.where(locd[:, None] == r, p, init), axis=0,
+                      keepdims=True)                      # (1, bp)
+            o_ref[r:r + 1, :] = comb(o_ref[r:r + 1, :], row)
 
 
 @functools.partial(jax.jit,
@@ -265,9 +290,9 @@ def _spmm_pallas_call(sblk, dblk, first, locs, locd, vals, xp, *,
         num_scalar_prefetch=3,
         grid=(c_total,),
         in_specs=[
-            pl.BlockSpec((1, bk), lambda c, sb, db, fi: (c, 0)),
-            pl.BlockSpec((1, bk), lambda c, sb, db, fi: (c, 0)),
-            pl.BlockSpec((1, bk), lambda c, sb, db, fi: (c, 0)),
+            pl.BlockSpec((None, 1, bk), lambda c, sb, db, fi: (c, 0, 0)),
+            pl.BlockSpec((None, 1, bk), lambda c, sb, db, fi: (c, 0, 0)),
+            pl.BlockSpec((None, 1, bk), lambda c, sb, db, fi: (c, 0, 0)),
             pl.BlockSpec((bs, bp), lambda c, sb, db, fi: (sb[c], 0)),
         ],
         out_specs=pl.BlockSpec((bn, bp),

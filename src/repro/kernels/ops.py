@@ -1,15 +1,12 @@
 """Public jit'd wrappers over the Pallas kernels with platform dispatch.
 
-On TPU the Pallas kernels run compiled; elsewhere (this CPU container) the
-``ref.py`` oracles execute.  ``force_pallas_interpret()`` lets tests route
-through the kernels in interpret mode regardless of platform; setting the
-``REPRO_PALLAS_INTERPRET`` environment variable does the same for whole
-processes (the CI kernel-parity step and ``make bench-kernel``).
+On TPU the Pallas kernels run compiled; on other backends the ``ref.py``
+oracles execute.  ``force_pallas_interpret()`` lets tests route a CPU host
+through the kernels in interpret mode.  Interpret mode follows from the
+backend alone (:func:`pallas_interpret`), so a TPU never takes it.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,24 +16,29 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.semiring_matmul import semiring_matmul_pallas
 from repro.kernels.ssm_scan import ssm_scan_pallas
 
-_FORCE_INTERPRET = bool(os.environ.get("REPRO_PALLAS_INTERPRET"))
+_FORCE_INTERPRET = False
 
 
 def force_pallas_interpret(on: bool = True) -> None:
-    """Route ops through the Pallas kernels in interpret mode (tests)."""
+    """Route ops through the Pallas kernels off-TPU, interpreted (tests)."""
     global _FORCE_INTERPRET
     _FORCE_INTERPRET = on
 
 
+def pallas_interpret() -> bool:
+    """True iff a Pallas kernel must run interpreted here: off a TPU."""
+    return jax.default_backend() != "tpu"
+
+
 def _use_pallas() -> bool:
-    return _FORCE_INTERPRET or jax.default_backend() == "tpu"
+    return _FORCE_INTERPRET or not pallas_interpret()
 
 
 def semiring_matmul(sr, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """C = A ⊕.⊗ B over semiring ``sr`` (2-D a, b)."""
     if _use_pallas():
         return semiring_matmul_pallas(a, b, sr_name=sr.name,
-                                      interpret=_FORCE_INTERPRET)
+                                      interpret=pallas_interpret())
     return ref.semiring_matmul_ref(sr, a, b)
 
 
@@ -53,7 +55,7 @@ def semiring_segment_reduce(sr, vals: jnp.ndarray,
         from repro.kernels.coo_segment import segment_reduce_pallas
         return segment_reduce_pallas(vals, segment_ids, num_segments,
                                      sr_name=sr.name,
-                                     interpret=_FORCE_INTERPRET)
+                                     interpret=pallas_interpret())
     return ref.segment_reduce_ref(sr, vals, segment_ids, num_segments)
 
 
@@ -68,7 +70,7 @@ def coo_spmm(rel, x, *, transpose: bool = False):
     from repro.kernels import coo_spmm as fused
     plan = fused.plan_geometry(rel, transpose=transpose)
     if _use_pallas():
-        return fused.spmm_pallas(plan, x, interpret=_FORCE_INTERPRET)
+        return fused.spmm_pallas(plan, x, interpret=pallas_interpret())
     return fused.spmm_host(plan, x)
 
 
@@ -78,7 +80,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, chunk=None,
     if _use_pallas():
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                       chunk=chunk, q_offset=q_offset,
-                                      interpret=_FORCE_INTERPRET)
+                                      interpret=pallas_interpret())
     return ref.attention_ref(q, k, v, causal=causal, window=window,
                              chunk=chunk, q_offset=q_offset)
 
@@ -99,7 +101,7 @@ def ssm_scan(a, b):
     if _use_pallas():
         t = a.shape[1]
         bt = 256 if t % 256 == 0 else _largest_pow2_divisor(t)
-        return ssm_scan_pallas(a, b, bt=bt, interpret=_FORCE_INTERPRET)
+        return ssm_scan_pallas(a, b, bt=bt, interpret=pallas_interpret())
     if SCAN_IMPL == "chunked":
         return ref.ssm_scan_chunked(a, b)
     return ref.ssm_scan_ref(a, b)

@@ -35,15 +35,18 @@ def semiring_matmul_ref(sr, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     chunk = int(max(1, min(m, (1 << 24) // max(1, k * n))))
     reduce_fn = jnp.min if name == "trop" else jnp.max
 
-    def piece(s):
-        blk = jax.lax.dynamic_slice_in_dim(a, s * chunk, chunk, 0)
-        return reduce_fn(blk[:, :, None] + b[None, :, :], axis=1)
-
     if chunk >= m:
         return reduce_fn(a[:, :, None] + b[None, :, :], axis=1)
     npad = (-m) % chunk
     a_p = jnp.pad(a, ((0, npad), (0, 0)), constant_values=sr.zero) if npad else a
     nchunks = (m + npad) // chunk
+
+    def piece(s):
+        # slices the padded rows: a clamped slice of ``a`` would re-read
+        # earlier rows for the last chunk
+        blk = jax.lax.dynamic_slice_in_dim(a_p, s * chunk, chunk, 0)
+        return reduce_fn(blk[:, :, None] + b[None, :, :], axis=1)
+
     out = jax.lax.map(piece, jnp.arange(nchunks))
     return out.reshape(-1, n)[:m]
 

@@ -10,8 +10,9 @@ adaptation of the Datalog hash-join inner loop:
 * the K loop is the innermost grid axis; the output tile is revisited and
   accumulated in place (grid iteration on TPU is sequential, so this is the
   canonical accumulate-in-VMEM pattern);
-* tropical tiles use a smaller bk so the (bm, bk, bn) broadcast stays in
-  VMEM (bm·bk·bn·4B ≤ 2 MiB for the default 128×32×128).
+* tropical tiles accumulate one rank-1 ``(min,+)`` update per k — an
+  A column against a B row, both plain 2-D broadcasts — since Mosaic has
+  no shape cast for the (bm, bk, bn) broadcast form.
 
 Oracle: ``repro.kernels.ref.semiring_matmul_ref`` — tests sweep shapes and
 semirings in interpret mode (CPU container; TPU is the compile target).
@@ -27,9 +28,9 @@ from jax.experimental import pallas as pl
 
 from repro.core import semiring as sr_mod
 
-# (bm, bk, bn) per semiring family
-_BLOCKS_DOT = (128, 128, 128)
-_BLOCKS_TROP = (128, 32, 128)
+# (bm, bk, bn): Mosaic takes a block whose last two dims are (8, 128)-
+# aligned or equal to the whole (padded) array's
+_BLOCKS = (128, 128, 128)
 
 
 def _dot_kernel(a_ref, b_ref, o_ref, *, k_steps: int, mode: str):
@@ -50,14 +51,10 @@ def _dot_kernel(a_ref, b_ref, o_ref, *, k_steps: int, mode: str):
 
 
 def _trop_kernel(a_ref, b_ref, o_ref, *, k_steps: int, mode: str):
-    """(min,+) / (max,+) tiles — VPU path with in-VMEM broadcast."""
+    """(min,+) / (max,+) tiles — VPU path, one rank-1 update per k."""
     kk = pl.program_id(2)
-    if mode == "trop":
-        init, red = jnp.inf, jnp.min
-        comb = jnp.minimum
-    else:
-        init, red = -jnp.inf, jnp.max
-        comb = jnp.maximum
+    init, comb = ((jnp.inf, jnp.minimum) if mode == "trop"
+                  else (-jnp.inf, jnp.maximum))
 
     @pl.when(kk == 0)
     def _init():
@@ -65,7 +62,9 @@ def _trop_kernel(a_ref, b_ref, o_ref, *, k_steps: int, mode: str):
 
     a = a_ref[...]  # (bm, bk)
     b = b_ref[...]  # (bk, bn)
-    part = red(a[:, :, None] + b[None, :, :], axis=1)
+    part = a[:, 0:1] + b[0:1, :]
+    for k in range(1, a.shape[1]):
+        part = comb(part, a[:, k:k + 1] + b[k:k + 1, :])
     o_ref[...] = comb(o_ref[...], part)
 
 
@@ -85,7 +84,7 @@ def semiring_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
     m, k = a.shape
     _, n = b.shape
     dot_path = sr_name in ("bool", "nat", "real")
-    bm, bk, bn = _BLOCKS_DOT if dot_path else _BLOCKS_TROP
+    bm, bk, bn = _BLOCKS
     bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
     # MXU/VPU want the minor dims 128-aligned; pad up when tiny
     if dot_path:

@@ -413,6 +413,9 @@ def _subst_sources(prog0: Program, prog1: Program,
 
 def main():
     from repro.datalog import datasets, programs
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=5000)

@@ -7,18 +7,27 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, **kw):
+    # Auto axes: the sharding rules place arrays with
+    # with_sharding_constraint, which refuses Explicit axes (the
+    # jax.make_mesh default in the pinned jax)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes), **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over the real local devices (tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))
 
 
 def make_graph_mesh(d: int | None = None):
@@ -37,7 +46,7 @@ def make_graph_mesh(d: int | None = None):
         raise ValueError(f"graph mesh needs {d} devices, have {n} "
                          f"(set XLA_FLAGS="
                          f"--xla_force_host_platform_device_count={d})")
-    return jax.make_mesh((d,), ("graph",), devices=jax.devices()[:d])
+    return _mesh((d,), ("graph",), devices=jax.devices()[:d])
 
 
 def make_datalog_mesh(data: int | None = None):
@@ -48,4 +57,4 @@ def make_datalog_mesh(data: int | None = None):
     them); the graph stays replicated.
     """
     n = data if data is not None else len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _mesh((n,), ("data",))

@@ -355,7 +355,7 @@ def _latency_plan(fam: Family):
             fam.latency_plan = (
                 plan if plan.strata[0].runner == "sparse_frontier"
                 else False)
-        except Exception:
+        except ValueError:  # the planner's "no such plan"; all else raises
             fam.latency_plan = False
     return fam.latency_plan
 

@@ -23,7 +23,6 @@ so padding rows contribute 0̄ ⊗ 1̄ = 0̄ to every reduction; scatters use
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -46,8 +45,8 @@ def _fused_spmm(rel: SparseRelation, b, *, transpose: bool, backend: str):
     from repro.kernels import coo_spmm, ops as kops
     plan = coo_spmm.plan_geometry(rel, transpose=transpose)
     if backend == "pallas":
-        interpret = kops._FORCE_INTERPRET or jax.default_backend() != "tpu"
-        return coo_spmm.spmm_pallas(plan, b, interpret=interpret)
+        return coo_spmm.spmm_pallas(plan, b,
+                                    interpret=kops.pallas_interpret())
     if backend == "fused":
         return coo_spmm.spmm_host(plan, b)
     raise ValueError(f"unknown SpMM backend {backend!r}")

@@ -452,7 +452,7 @@ def _pallas_fixpoint(edges, init, sr, max_iters, *, warm=None):
     """
     from repro.kernels import coo_spmm, ops as kops
 
-    interp = kops._FORCE_INTERPRET or jax.default_backend() != "tpu"
+    interp = kops.pallas_interpret()
     plan = coo_spmm.plan_geometry(edges, transpose=True)
     batched = np.ndim(init if warm is None else warm[0]) == 2
     key = ("fixpoint", batched, warm is None, max_iters, interp)
@@ -553,7 +553,7 @@ def _fused_resume_chunk(edges, y0, d0, it0, max_iters, backend):
     sr = sr_mod.get(edges.semiring)
     plan = coo_spmm.plan_geometry(edges, transpose=True)
     if backend == "pallas":
-        interp = kops._FORCE_INTERPRET or jax.default_backend() != "tpu"
+        interp = kops.pallas_interpret()
         key = ("chunk", max_iters, interp)
         fn = plan.jit_cache.get(key)
         if fn is None:

@@ -3,8 +3,7 @@
 * Pallas kernel parity vs the jnp gather→⊗→segment-⊕ oracle across all
   four semirings, ragged nnz tails (empty / duplicate / off-block
   shapes), (B, n) batching, and both transpose orientations — in
-  interpret mode so CI's CPU job exercises the kernel path
-  (``make test-kernel`` runs this file under REPRO_PALLAS_INTERPRET=1).
+  interpret mode so CI's CPU job exercises the kernel path.
 * Host fused executors (``spmm_host``, packed-𝔹 ``bool_round_packed``)
   against the same oracle.
 * Fixpoint parity — values AND per-row iteration counts — of the
@@ -340,14 +339,56 @@ def test_planner_pick_flips_with_measured_constants(monkeypatch):
         sp.rejected["sparse_frontier_pallas"]
 
 
+@pytest.mark.parametrize("sr_name", SEMIRINGS)
+@pytest.mark.parametrize("transpose", [True, False])
+def test_padded_slots_counts_the_geometry(sr_name, transpose):
+    """The planner's slot count, taken without building the geometry,
+    equals the chunk slots the kernel sweeps."""
+    rel = _relation(3000, 3, sr_name, seed=7)
+    plan = coo_spmm.plan_geometry(rel, transpose=transpose)
+    locs = coo_spmm._chunk_geometry(plan)[3]
+    assert coo_spmm.padded_slots(rel, transpose=transpose) \
+        == locs.shape[0] * plan.bk
+
+
+@pytest.mark.skipif(not CPU, reason="crossover constants are per-host")
+def test_planner_prices_padded_geometry(monkeypatch):
+    """On the kernel path the candidate is priced by its padded slots:
+    padding that outweighs the per-slot win is rejected, and so is a
+    geometry larger than device memory, each with its reason."""
+    monkeypatch.setattr(kops, "_FORCE_INTERPRET", True)
+    plan = _bool_plan(5000)
+    why = plan.strata[0].rejected["sparse_frontier_pallas"]
+    assert "slots for nnz(E)=" in why and "per-slot win" in why
+    assert why in planner.explain(plan)
+    monkeypatch.setitem(planner.SPMM_COST.host_speedup, "bool", 1e3)
+    assert _bool_plan(5000).strata[0].runner == "sparse_frontier_pallas"
+    monkeypatch.setattr(planner, "_device_bytes", lambda: 1e5)
+    plan = _bool_plan(5000)
+    why = plan.strata[0].rejected["sparse_frontier_pallas"]
+    assert "geometry does not fit" in why
+    assert why in planner.explain(plan)
+
+
+def test_tpu_never_interprets(monkeypatch):
+    """Interpret mode follows from the backend alone: forcing the kernel
+    path on a TPU still compiles it."""
+    monkeypatch.setattr(kops, "_FORCE_INTERPRET", True)
+    assert kops._use_pallas()
+    assert kops.pallas_interpret() == CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kops._use_pallas() and not kops.pallas_interpret()
+
+
 @pytest.mark.skipif(not CPU, reason="crossover constants are per-host")
 def test_pallas_plan_answers_match_naive(monkeypatch):
     """End-to-end: the sparse_frontier_pallas plan's answers (and its
     compile_batched unit) are bit-exact vs the jnp runners.  The
     crossover floor is lowered so the cell stays small enough for
-    interpret mode (REPRO_PALLAS_INTERPRET CI runs execute the kernel
-    path here, not the host loop)."""
+    interpret mode, which the forced kernel path runs here in place of
+    the host loop."""
     monkeypatch.setattr(planner.SPMM_COST, "min_nnz", 1024.0)
+    monkeypatch.setattr(kops, "_FORCE_INTERPRET", True)
     n = 800
     g = datasets.erdos_renyi(n, 3.0, seed=2)
     schema = programs.bm(a=0).original.schema

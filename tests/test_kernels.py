@@ -42,6 +42,21 @@ def test_semiring_matmul_kernel(sr_name, shape):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("sr_name", ["trop", "maxplus"])
+def test_semiring_matmul_ref_chunked_tail(sr_name):
+    """The oracle's row-chunked path (m·k·n past 2^24) with a ragged
+    last chunk equals a plain numpy (min|max, +) product."""
+    m, k, n = 300, 200, 300
+    rng = np.random.default_rng(11)
+    a = rng.integers(0, 9, (m, k)).astype(np.float32)
+    b = rng.integers(0, 9, (k, n)).astype(np.float32)
+    red = np.min if sr_name == "trop" else np.max
+    want = red(a[:, :, None] + b[None, :, :], axis=1)
+    got = ref.semiring_matmul_ref(sr_mod.get(sr_name), jnp.asarray(a),
+                                  jnp.asarray(b))
+    assert np.array_equal(np.asarray(got), want)
+
+
 @pytest.mark.parametrize("tq,tk,hq,hkv,d", [
     (64, 64, 4, 4, 32),     # MHA
     (64, 64, 8, 2, 32),     # GQA
