@@ -138,9 +138,11 @@ class Runner:
     def serve_chunk_fn(self, chunk_iters: int):
         """The serve scheduler's compiled unit: ``(e, y, d, it) →
         (y, d, it)`` advancing the slot-pool carry by ``chunk_iters``
-        rounds (:mod:`repro.serve.slots`)."""
-        return jax.jit(lambda e, y, d, it: fx._resume_chunk(
-            e, y, d, it, max_iters=chunk_iters))
+        rounds (:mod:`repro.serve.slots`); named, so a device trace
+        calls its program ``jit_fixpoint_chunk``."""
+        def fixpoint_chunk(e, y, d, it):
+            return fx._resume_chunk(e, y, d, it, max_iters=chunk_iters)
+        return jax.jit(fixpoint_chunk)
 
 
 def _mesh_d(mesh) -> int:

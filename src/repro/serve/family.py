@@ -39,6 +39,7 @@ from repro.core import semiring as sr_mod
 from repro.core.program import Program
 from repro.serve.cache import LRUCache
 from repro.sparse.coo import SparseRelation
+from repro.trace import span
 
 
 @dataclasses.dataclass
@@ -173,10 +174,11 @@ def build_family(name: str, make_program: Callable[[int], Program],
     """
     template = make_program(template_source)
     hints = dict(template.sort_hints)
-    plan = planner.plan_program(
-        template, db, planner.PlanHints(sorts=hints),
-        objective="throughput", edges=edges,
-        adapt_storage=False, require_vector=True, mesh=graph_mesh)
+    with span("register.plan"):
+        plan = planner.plan_program(
+            template, db, planner.PlanHints(sorts=hints),
+            objective="throughput", edges=edges,
+            adapt_storage=False, require_vector=True, mesh=graph_mesh)
     edges = planner.materialize_edges(plan, db, hints)
     n = db.dom(plan.strata[0].vf.out_sort)
     # numpy twin of the relations: per-request init evaluation runs

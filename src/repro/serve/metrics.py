@@ -93,39 +93,3 @@ class RequestMetrics:
                 "compute": self.compute.summary(),
                 "total": self.total.summary()}
 
-
-class FrontierMetrics:
-    """Per-family chunk-boundary frontier observations (DESIGN.md §10).
-
-    The continuous scheduler records the slot pool's live-Δ count after
-    every chunk it steps — the same ``FrontierStats`` signal the
-    adaptive executor re-prices runners from — so operators can see a
-    family's frontier drift (collapse → hub re-explosion) from
-    ``stats()`` without instrumenting the pool.  Fixed memory: scalars
-    plus one running sum, no per-chunk history.
-    """
-
-    def __init__(self):
-        self.chunks = 0
-        self.last_nnz = 0
-        self.last_density = 0.0
-        self.peak_nnz = 0
-        self._nnz_sum = 0
-
-    def record(self, nnz: int, density: float) -> None:
-        self.chunks += 1
-        self.last_nnz = int(nnz)
-        self.last_density = float(density)
-        self._nnz_sum += int(nnz)
-        if nnz > self.peak_nnz:
-            self.peak_nnz = int(nnz)
-
-    def summary(self) -> dict:
-        return {
-            "chunks": self.chunks,
-            "last_nnz": self.last_nnz,
-            "last_density": self.last_density,
-            "peak_nnz": self.peak_nnz,
-            "mean_nnz": (self._nnz_sum / self.chunks) if self.chunks
-            else 0.0,
-        }

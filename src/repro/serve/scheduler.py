@@ -34,7 +34,9 @@ vLLM-style loop the per-row convergence masks were built for:
   fix for BENCH_serve.json.
 * **metrics**: queue/compute/total latency of every request stream into
   the streaming histograms of :mod:`repro.serve.metrics`; ``stats()``
-  exposes p50/p95/p99 plus counter totals and per-family gauges.
+  exposes p50/p95/p99 plus counter totals (``rounds`` and
+  ``carry_bytes`` among them) and per-family gauges (``frontier_nnz``
+  read on demand); the stages of a round are :mod:`repro.trace` spans.
 
 Families whose operator is dense or graph-sharded have no columnwise
 splice (dense batched runners carry no per-row state the host can cheaply
@@ -57,9 +59,10 @@ from repro.serve import family as fam_mod
 from repro.serve.cache import LRUCache
 from repro.serve.family import (Family, QueryRequest, UpdateRequest,
                                 bucket)
-from repro.serve.metrics import FrontierMetrics, RequestMetrics
+from repro.serve.metrics import RequestMetrics
 from repro.serve.slots import SlotPool
 from repro.sparse.coo import SparseRelation
+from repro.trace import counters, span
 
 
 class BackpressureError(RuntimeError):
@@ -85,8 +88,6 @@ class _FamilyState:
     next_deliver: int = 0        # FIFO delivery cursor
     done: dict = dataclasses.field(default_factory=dict)
     served: int = 0
-    frontier: FrontierMetrics = dataclasses.field(
-        default_factory=FrontierMetrics)
 
 
 class ContinuousServer:
@@ -113,6 +114,7 @@ class ContinuousServer:
             "warm_hits": 0, "answers_repaired": 0, "answers_dropped": 0,
             "admitted": 0, "evicted": 0, "chunks": 0, "migrated": 0,
             "latency_routed": 0, "packed_fallback": 0,
+            "rounds": 0, "carry_bytes": 0,
         }
 
     # -- registration -------------------------------------------------------
@@ -190,10 +192,11 @@ class ContinuousServer:
                 self._admit(fs, delivered)
                 if fs.pool is None or fs.pool.occupied == 0:
                     break
-                fs.pool.step(self.chunk_iters)
+                rounds, moved = fs.pool.step(self.chunk_iters)
                 self._counters["chunks"] += 1
-                fs.frontier.record(fs.pool.frontier_nnz(),
-                                   fs.pool.frontier_density())
+                self._counters["rounds"] += rounds
+                self._counters["carry_bytes"] += moved
+                counters(rounds=rounds, carry_bytes=moved)
                 self._harvest(fs, delivered)
         return delivered
 
@@ -244,6 +247,10 @@ class ContinuousServer:
         return n
 
     def _admit(self, fs: _FamilyState, delivered: list) -> None:
+        with span("serve.admit"):
+            self._admit_queued(fs, delivered)
+
+    def _admit_queued(self, fs: _FamilyState, delivered: list) -> None:
         fam = fs.fam
         while fs.queue and isinstance(fs.queue[0], QueryRequest):
             req = fs.queue[0]
@@ -347,13 +354,14 @@ class ContinuousServer:
                            chunk_fn_factory=chunk_fn_factory)
 
     def _harvest(self, fs: _FamilyState, delivered: list) -> None:
-        for req, y, iters in fs.pool.harvest():
-            req.converged_s = time.perf_counter()
-            req.result = y
-            req.iters = iters
-            self._counters["evicted"] += 1
-            self._remember(fs.fam, req.source, y)
-            self._finish(fs, req, delivered)
+        with span("serve.harvest"):
+            for req, y, iters in fs.pool.harvest():
+                req.converged_s = time.perf_counter()
+                req.result = y
+                req.iters = iters
+                self._counters["evicted"] += 1
+                self._remember(fs.fam, req.source, y)
+                self._finish(fs, req, delivered)
 
     def _serve_solo(self, fs: _FamilyState, req: QueryRequest, init,
                     delivered: list) -> None:
@@ -470,6 +478,7 @@ class ContinuousServer:
                    "weight": fs.weight,
                    "warm_answers": len(fs.fam.answers),
                    "warm_evictions": fs.fam.answers.evictions,
-                   "frontier": fs.frontier.summary()}
+                   "frontier_nnz": (fs.pool.frontier_nnz() if fs.pool
+                                    else 0)}
             for name, fs in self._families.items()}
         return out

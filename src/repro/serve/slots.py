@@ -50,11 +50,13 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
 from repro.core import semiring as sr_mod
 from repro.serve.family import Family, QueryRequest
 from repro.sparse.coo import SparseRelation
+from repro.trace import span
 
 #: level-sync admissibility: weights must be positive integers ≤ this
 #: (the ring buffer holds wmax+1 frontier levels; huge weights would
@@ -95,6 +97,9 @@ class BitsetBoolStepper:
     dst-sorted :class:`~repro.kernels.coo_spmm.SpmmPlan`), so the serve
     hot loop and the planner-priced backend cannot drift apart.
     """
+
+    #: the carry never leaves the host
+    carry_bytes = 0
 
     def __init__(self, edges: SparseRelation, n: int, b: int,
                  geom_cache: dict | None = None):
@@ -157,6 +162,8 @@ class LevelSyncTropStepper:
     are not positive integers ≤ :data:`TROP_WMAX_CAP` — selection then
     falls back to the jax stepper.
     """
+
+    carry_bytes = 0
 
     def __init__(self, edges: SparseRelation, n: int, b: int,
                  geom_cache: dict | None = None):
@@ -272,6 +279,7 @@ class JaxChunkStepper:
         self.y = np.full((b, n), sr.zero, sr.dtype)
         self.d = np.full((b, n), sr.zero, sr.dtype)
         self.it = np.zeros(b, np.int32)
+        self.carry_bytes = 0
 
     def admit(self, j: int, init: np.ndarray) -> bool:
         zero_row = np.full(self.n, self._sr.zero, self._sr.dtype)
@@ -292,15 +300,31 @@ class JaxChunkStepper:
                                          self._sr.dtype)).sum())
 
     def step(self, k: int) -> None:
-        if not self.live_lanes().any():
-            return
-        y, d, it = self._chunk(self.edges.as_jnp(), self.y, self.d,
-                               self.it)
-        # np.array, not asarray: jax hands back read-only zero-copy
-        # views on CPU, and admit/release scribble rows in place
-        self.y = np.array(y)
-        self.d = np.array(d)
-        self.it = np.array(it, np.int32)
+        """One chunk.  ``carry_bytes`` counts the bytes it moved between
+        host and device: every argument not already on the device, and
+        the three outputs copied back."""
+        with span("pool.scan"):
+            if not self.live_lanes().any():
+                return
+        with span("pool.upload"):
+            edges = self.edges
+            moved = sum(np.asarray(x).nbytes
+                        for x in (edges.coords, edges.values, edges.nnz)
+                        if not isinstance(x, jax.Array))
+            edges = edges.as_jnp()
+            carry = (self.y, self.d, self.it)
+            moved += sum(x.nbytes for x in carry)
+            y, d, it = jax.block_until_ready(jax.device_put(carry))
+        with span("pool.run"):
+            y, d, it = jax.block_until_ready(self._chunk(edges, y, d, it))
+        with span("pool.download"):
+            # np.array, not asarray: jax hands back read-only zero-copy
+            # views on CPU, and admit/release scribble rows in place
+            self.y = np.array(y)
+            self.d = np.array(d)
+            self.it = np.array(it, np.int32)
+        self.carry_bytes += (moved + self.y.nbytes + self.d.nbytes
+                             + self.it.nbytes)
 
     def extract(self, j: int) -> tuple[np.ndarray, int]:
         return self.y[j].copy(), int(self.it[j])
@@ -318,8 +342,6 @@ def build_stepper(fam: Family, b: int, *, host_kernels: bool,
     ``chunk_fn_factory()`` lazily supplies the compiled jax chunk
     function (so host-kernel pools never touch the compile cache).
     """
-    import jax
-
     edges = fam.edges
     if not isinstance(edges, SparseRelation):
         raise ValueError("slot pools need a sparse linear operator")
@@ -374,17 +396,22 @@ class SlotPool:
         self.slots[j] = req
         return True
 
-    def step(self, k: int) -> None:
-        self.stepper.step(k)
+    def step(self, k: int) -> tuple[int, int]:
+        """Step one chunk of at most ``k`` rounds; returns the rounds
+        the device ran and the carry bytes moved between host and
+        device.  Rounds are the most any lane advanced: a lane whose Δ
+        row is 0̄ stays 0̄, so the lane live in the last round was live
+        in every round."""
+        st = self.stepper
+        it, moved = st.it.copy(), st.carry_bytes
+        st.step(k)
+        return (int((st.it - it).max(initial=0)),
+                st.carry_bytes - moved)
 
     def frontier_nnz(self) -> int:
-        """Live Δ entries across all lanes — the chunk-boundary frontier
-        observation the scheduler streams into its per-family
-        :class:`~repro.serve.metrics.FrontierMetrics`."""
+        """Live Δ entries across all lanes: one scan of the carry, on
+        demand (``stats()["families"][f]["frontier_nnz"]``)."""
         return self.stepper.frontier_nnz()
-
-    def frontier_density(self) -> float:
-        return self.frontier_nnz() / float(self.b * self.fam.n or 1)
 
     def harvest(self) -> list[tuple[QueryRequest, np.ndarray, int]]:
         """Evict every occupied slot whose convergence mask fired:

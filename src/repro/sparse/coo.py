@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import semiring as sr_mod
+from repro.trace import span
 
 Array = jnp.ndarray
 
@@ -138,11 +139,13 @@ class SparseRelation:
         assert len(coords) == len(values), (coords.shape, values.shape)
         # coalesce: ⊕-combine duplicate keys
         if len(coords):
-            uniq, inv = np.unique(coords, axis=0, return_inverse=True)
-            if len(uniq) != len(coords):
-                merged = np.full(len(uniq), sr.zero, sr.dtype)
-                _NP_COMBINE[semiring].at(merged, inv.reshape(-1), values)
-                coords, values = uniq, merged
+            with span("ingest.coalesce"):
+                uniq, inv = np.unique(coords, axis=0, return_inverse=True)
+                if len(uniq) != len(coords):
+                    merged = np.full(len(uniq), sr.zero, sr.dtype)
+                    _NP_COMBINE[semiring].at(merged, inv.reshape(-1),
+                                             values)
+                    coords, values = uniq, merged
         # drop explicit zeros (0̄ tuples are absent by definition)
         if len(values):
             live = values != sr.zero if semiring != "bool" else values

@@ -558,11 +558,12 @@ def _fused_resume_chunk(edges, y0, d0, it0, max_iters, backend):
         fn = plan.jit_cache.get(key)
         if fn is None:
             ej = edges.as_jnp()
-            fn = jax.jit(lambda y, d, it: _chunk_loop(
-                ej, y, d, it, sr, max_iters,
-                advance=lambda dd: coo_spmm.spmm_pallas(
-                    plan, dd, interpret=interp)))
-            plan.jit_cache[key] = fn
+
+            def fixpoint_chunk(y, d, it):
+                return _chunk_loop(ej, y, d, it, sr, max_iters,
+                                   advance=lambda dd: coo_spmm.spmm_pallas(
+                                       plan, dd, interpret=interp))
+            fn = plan.jit_cache[key] = jax.jit(fixpoint_chunk)
         return fn(jnp.asarray(y0), jnp.asarray(d0), jnp.asarray(it0))
     if backend != "fused":
         raise ValueError(f"unknown fixpoint backend {backend!r}")
@@ -608,7 +609,9 @@ def _chunk_loop(edges, y0, d0, it0, sr, max_iters, *, advance=None):
         y, d, it_rows, it = carry
         live = jnp.any(d != sr.zero, axis=0)
         y_new = sh.constrain(sr.add(y, d), ("vertex", "query_batch"))
-        d_new = sr.minus(adv(d), y_new)
+        with jax.named_scope("advance"):
+            e_d = adv(d)
+        d_new = sr.minus(e_d, y_new)
         d_new = sh.constrain(d_new, ("vertex", "query_batch"))
         return y_new, d_new, it_rows + live, it + 1
 
