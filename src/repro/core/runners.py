@@ -140,9 +140,7 @@ class Runner:
         (y, d, it)`` advancing the slot-pool carry by ``chunk_iters``
         rounds (:mod:`repro.serve.slots`); named, so a device trace
         calls its program ``jit_fixpoint_chunk``."""
-        def fixpoint_chunk(e, y, d, it):
-            return fx._resume_chunk(e, y, d, it, max_iters=chunk_iters)
-        return jax.jit(fixpoint_chunk)
+        return fx.CompiledChunk(chunk_iters)
 
 
 def _mesh_d(mesh) -> int:
@@ -224,11 +222,11 @@ class JitRunner(_SparseRunner):
         key = ("chunk", self.name, budget)
         fn = ctx.extras.get(key)
         if fn is None:
-            sr = sr_mod.get(ctx.semiring)
-            ej = ctx.edges.as_jnp()
-            fn = ctx.extras[key] = jax.jit(
-                lambda y, d, it: fx._chunk_loop(ej, y, d, it, sr, budget))
-        y, d, it = fn(np.asarray(state.y), np.asarray(state.delta),
+            fn = ctx.extras[key] = fx.CompiledChunk(budget)
+        ej = ctx.extras.get("jnp_edges")
+        if ej is None:
+            ej = ctx.extras["jnp_edges"] = ctx.edges.as_jnp()
+        y, d, it = fn(ej, np.asarray(state.y), np.asarray(state.delta),
                       np.asarray(state.iters, np.int32))
         st = fx.FixpointState(y, d, it, state.semiring, state.batched)
         return st, st.stats()

@@ -34,9 +34,10 @@ vLLM-style loop the per-row convergence masks were built for:
   fix for BENCH_serve.json.
 * **metrics**: queue/compute/total latency of every request stream into
   the streaming histograms of :mod:`repro.serve.metrics`; ``stats()``
-  exposes p50/p95/p99 plus counter totals (``rounds`` and
-  ``carry_bytes`` among them) and per-family gauges (``frontier_nnz``
-  read on demand); the stages of a round are :mod:`repro.trace` spans.
+  exposes p50/p95/p99 plus counter totals (``rounds``,
+  ``packed_rounds`` and ``carry_bytes`` among them) and per-family
+  gauges (``frontier_nnz`` read on demand); the stages of a round are
+  :mod:`repro.trace` spans.
 
 Families whose operator is dense or graph-sharded have no columnwise
 splice (dense batched runners carry no per-row state the host can cheaply
@@ -114,7 +115,7 @@ class ContinuousServer:
             "warm_hits": 0, "answers_repaired": 0, "answers_dropped": 0,
             "admitted": 0, "evicted": 0, "chunks": 0, "migrated": 0,
             "latency_routed": 0, "packed_fallback": 0,
-            "rounds": 0, "carry_bytes": 0,
+            "rounds": 0, "packed_rounds": 0, "carry_bytes": 0,
         }
 
     # -- registration -------------------------------------------------------
@@ -192,11 +193,13 @@ class ContinuousServer:
                 self._admit(fs, delivered)
                 if fs.pool is None or fs.pool.occupied == 0:
                     break
-                rounds, moved = fs.pool.step(self.chunk_iters)
+                rounds, packed, moved = fs.pool.step(self.chunk_iters)
                 self._counters["chunks"] += 1
                 self._counters["rounds"] += rounds
+                self._counters["packed_rounds"] += packed
                 self._counters["carry_bytes"] += moved
-                counters(rounds=rounds, carry_bytes=moved)
+                counters(rounds=rounds, packed_rounds=packed,
+                         carry_bytes=moved)
                 self._harvest(fs, delivered)
         return delivered
 

@@ -17,8 +17,9 @@ Three interchangeable chunk steppers implement the same GSN body:
 
 * :class:`JaxChunkStepper` — the general path: a jitted
   ``resume_fixpoint_chunk`` (one SpMM per round, chunked
-  ``lax.while_loop``), compiled once per ``(plan.signature, B-bucket,
-  D)`` exactly like the packed server's runners.
+  ``lax.while_loop``; for 𝔹 the packed pull round), compiled once per
+  ``(plan.signature, B-bucket, D)`` exactly like the packed server's
+  runners.
 * :class:`BitsetBoolStepper` — boolean semiring on CPU: the B query
   lanes live as bits of ``⌈B/64⌉`` uint64 words per vertex, and a round
   is the fused kernel's packed-𝔹 advance
@@ -100,6 +101,8 @@ class BitsetBoolStepper:
 
     #: the carry never leaves the host
     carry_bytes = 0
+    #: every round is a bit-packed pull over dst-sorted edges
+    packed = True
 
     def __init__(self, edges: SparseRelation, n: int, b: int,
                  geom_cache: dict | None = None):
@@ -164,6 +167,7 @@ class LevelSyncTropStepper:
     """
 
     carry_bytes = 0
+    packed = False
 
     def __init__(self, edges: SparseRelation, n: int, b: int,
                  geom_cache: dict | None = None):
@@ -267,13 +271,17 @@ class LevelSyncTropStepper:
 
 class JaxChunkStepper:
     """The general chunk stepper: host-resident (B, n) carry advanced by
-    a jitted bounded slice of the batched GSN loop."""
+    a jitted bounded slice of the batched GSN loop.  ``packed`` says
+    whether the chunk's rounds take the packed pull round, as a
+    :class:`~repro.sparse.fixpoint.CompiledChunk` reports it."""
 
     def __init__(self, edges: SparseRelation, n: int, b: int,
                  chunk_fn):
         self.edges = edges
         self.n, self.b = n, b
         self._chunk = chunk_fn          # (edges, y, d, it) -> (y, d, it)
+        packs = getattr(chunk_fn, "packs", None)
+        self.packed = packs is not None and packs(edges)
         sr = sr_mod.get(edges.semiring, lib="np")
         self._sr = sr
         self.y = np.full((b, n), sr.zero, sr.dtype)
@@ -396,17 +404,17 @@ class SlotPool:
         self.slots[j] = req
         return True
 
-    def step(self, k: int) -> tuple[int, int]:
+    def step(self, k: int) -> tuple[int, int, int]:
         """Step one chunk of at most ``k`` rounds; returns the rounds
-        the device ran and the carry bytes moved between host and
-        device.  Rounds are the most any lane advanced: a lane whose Δ
-        row is 0̄ stays 0̄, so the lane live in the last round was live
-        in every round."""
+        the device ran, those of them that took the packed pull round,
+        and the carry bytes moved between host and device.  Rounds are
+        the most any lane advanced: a lane whose Δ row is 0̄ stays 0̄, so
+        the lane live in the last round was live in every round."""
         st = self.stepper
         it, moved = st.it.copy(), st.carry_bytes
         st.step(k)
-        return (int((st.it - it).max(initial=0)),
-                st.carry_bytes - moved)
+        rounds = int((st.it - it).max(initial=0))
+        return rounds, rounds if st.packed else 0, st.carry_bytes - moved
 
     def frontier_nnz(self) -> int:
         """Live Δ entries across all lanes: one scan of the carry, on

@@ -11,6 +11,9 @@ The dense engine lowers a binary join-and-aggregate to ``C = A ⊕.⊗ B``
   (Pallas segment-reduce on TPU, jnp scatter elsewhere).  Cost O(nnz),
   independent of the dense key-space size.
 * ``spmm`` — sparse matrix × dense matrix, same scheme with row payloads.
+  The bounded fixpoint chunk no longer runs it for 𝔹: there a round is
+  a pull of bit-packed words over dst-sorted edges, with no scatter
+  (:mod:`repro.sparse.fixpoint`).
 * ``spmspm`` — sparse × sparse → sparse, a host/numpy sort-merge join on
   the contracted key (the eager ``backend="np"`` world of the
   synthesizer); on-device callers densify one side instead, since output

@@ -34,11 +34,20 @@ gathers/scatters move contiguous B-wide rows and the batch axis can be
 sharded across devices (``query_batch`` logical axis).  ``iters`` comes
 back as a ``(B,)`` per-source vector.  Rows whose init is all-0̄ are
 inert — the serve loop uses them as batch padding.
+
+**𝔹 chunks pull packed words:** the bounded chunk (:func:`_chunk_loop`,
+the serve pools' :class:`CompiledChunk`) scatters no B-wide rows for 𝔹.
+With no ``advance=`` override and no active mesh it packs the lanes 32
+to a uint32 word and runs each round as a pull over the operator's
+dst-sorted :class:`PullView` (gather, segmented OR, read of run ends),
+built once per operator on the device.  Other semirings keep the
+gather/scatter SpMM.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 import weakref
 
@@ -300,9 +309,10 @@ def resume_fixpoint_chunk(edges: SparseRelation, y0, d0, it0, *,
 
 
 def _resume_chunk(edges: SparseRelation, y0, d0, it0, *,
-                  max_iters: int, backend: str = "jnp"):
+                  max_iters: int, backend: str = "jnp", view=None):
     """The chunk body behind :func:`fixpoint`'s ``budget=`` path and the
-    (deprecated) :func:`resume_fixpoint_chunk` shim."""
+    (deprecated) :func:`resume_fixpoint_chunk` shim.  ``view`` is the
+    operator's :class:`PullView` where the caller looked it up."""
     if edges.arity != 2 or edges.shape[0] != edges.shape[1]:
         raise ValueError(f"recursive expansion needs a square binary edge "
                          f"relation, got shape {edges.shape}")
@@ -312,7 +322,8 @@ def _resume_chunk(edges: SparseRelation, y0, d0, it0, *,
                          "GSN needs an idempotent complete lattice")
     if backend != "jnp":
         return _fused_resume_chunk(edges, y0, d0, it0, max_iters, backend)
-    return _chunk_loop(edges.as_jnp(), y0, d0, it0, sr, max_iters)
+    return _chunk_loop(edges.as_jnp(), y0, d0, it0, sr, max_iters,
+                       view=view)
 
 
 def _dispatch(edges, init, *, max_iters, mode, warm=None, backend="jnp"):
@@ -592,10 +603,18 @@ def _fused_resume_chunk(edges, y0, d0, it0, max_iters, backend):
     return jnp.asarray(y), jnp.asarray(d), jnp.asarray(it_rows)
 
 
-def _chunk_loop(edges, y0, d0, it0, sr, max_iters, *, advance=None):
-    """The traceable chunk body shared by the jnp and pallas chunks."""
+def _chunk_loop(edges, y0, d0, it0, sr, max_iters, *, advance=None,
+                view=None):
+    """The traceable chunk body shared by the jnp, dense and pallas
+    chunks.  A 𝔹 chunk with no ``advance`` override and no active mesh
+    runs the packed pull round (:func:`_packed_chunk_loop`) over
+    ``view``, which is built here, inside the trace, when not given."""
     from repro.distributed import sharding as sh
 
+    if takes_pull_round(sr.name, advance):
+        return _packed_chunk_loop(view if view is not None
+                                  else pull_view(edges),
+                                  y0, d0, it0, max_iters)
     adv = advance or (lambda d: contract.spmm(edges, d, transpose=True))
     y = sh.constrain(jnp.asarray(y0).T, ("vertex", "query_batch"))
     d = sh.constrain(jnp.asarray(d0).T, ("vertex", "query_batch"))
@@ -618,6 +637,205 @@ def _chunk_loop(edges, y0, d0, it0, sr, max_iters, *, advance=None):
     y, d, it_rows, _ = jax.lax.while_loop(
         cond, body, (y, d, it_rows, jnp.asarray(0)))
     return y.T, d.T, it_rows
+
+
+# --------------------------------------------------------------------------
+# 𝔹 pull round: bit-packed lanes over a dst-sorted view of the edges
+# --------------------------------------------------------------------------
+#
+# For 𝔹, ⊕ is OR, so 32 query lanes pack into one uint32 word per vertex
+# and a round pulls words instead of pushing B-wide rows: gather each
+# edge's source word in destination order, OR within each destination's
+# run, read the run's last position.  No scatter, and no per-round sort
+# of the destination indices, which XLA's scatter-max makes every round.
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class PullView:
+    """The operator's edges sorted by destination, for the packed round.
+
+    ``src`` is each edge's source in that order; an edge whose value is
+    0̄ (or a padding slot) has source ``n``, which reads no word.  ``off``
+    is each edge's position within its destination's run, and ``last``
+    each vertex's last position in the order (``cap`` for a vertex with
+    no in-edges, which reads 0).  ``steps`` (static) doubling passes
+    cover the longest run.
+    """
+
+    src: object     # (cap,) int32
+    off: object     # (cap,) int32
+    last: object    # (n,) int32
+    steps: int
+
+    def tree_flatten(self):
+        return (self.src, self.off, self.last), (self.steps,)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+
+def takes_pull_round(semiring: str, advance=None) -> bool:
+    """Whether a chunk runs the packed pull round: 𝔹, no ``advance``
+    override, and no active mesh (whose ``query_batch`` axis packing
+    would merge)."""
+    from repro.distributed import sharding as sh
+    return semiring == "bool" and advance is None \
+        and sh.current_mesh() is None
+
+
+_PULL_CACHE: dict[tuple[int, int], tuple[object, object, PullView]] = {}
+
+
+def pull_view(edges: SparseRelation) -> PullView:
+    """The operator's :class:`PullView`: built on the device with one
+    sort, and cached per (coords, values) buffer pair, weakly, like the
+    CSR index.  Traced edges build it inside the trace, uncached."""
+    if isinstance(edges.coords, jax.core.Tracer) or \
+            isinstance(edges.values, jax.core.Tracer):
+        return _build_pull_view(edges, concrete=False)
+    key = (id(edges.coords), id(edges.values))
+    ent = _PULL_CACHE.get(key)
+    if ent is not None and ent[0]() is edges.coords \
+            and ent[1]() is edges.values:
+        return ent[2]
+    view = _build_pull_view(edges, concrete=True)
+
+    def _evict(ref, k=key):
+        cur = _PULL_CACHE.get(k)
+        if cur is not None and ref in (cur[0], cur[1]):
+            _PULL_CACHE.pop(k, None)
+
+    try:
+        _PULL_CACHE[key] = (weakref.ref(edges.coords, _evict),
+                            weakref.ref(edges.values, _evict), view)
+    except TypeError:  # pragma: no cover — all our buffers are weakrefable
+        pass
+    return view
+
+
+def _build_pull_view(edges: SparseRelation, *, concrete: bool) -> PullView:
+    src, off, last, longest = _pull_arrays(edges.coords, edges.values,
+                                           n=edges.shape[0])
+    # a concrete view takes as many doubling passes as its longest run
+    # needs; a traced one as many as the capacity
+    longest = int(longest) if concrete else src.shape[0]
+    return PullView(src, off, last, max(0, longest - 1).bit_length())
+
+
+@functools.partial(jax.jit, static_argnames="n")
+def _pull_arrays(coords, values, *, n: int):
+    coords = coords.astype(jnp.int32)
+    cap = coords.shape[0]
+    src = jnp.where(values.astype(bool), coords[:, 0], n)
+    # padding slots carry dst = n, so they sort past every vertex
+    dst, src = jax.lax.sort((coords[:, 1], src), num_keys=1)
+    pos = jnp.arange(cap, dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool), dst[1:] != dst[:-1]])
+    off = pos - jax.lax.cummax(jnp.where(first, pos, 0))
+    vertex = jnp.arange(n, dtype=jnp.int32)
+    last = jnp.searchsorted(dst, vertex, side="right").astype(jnp.int32) - 1
+    has_in = (last >= 0) & (dst[jnp.maximum(last, 0)] == vertex)
+    longest = jnp.max(jnp.where(dst < n, off, -1)) + 1
+    return src, off, jnp.where(has_in, last, cap), longest
+
+
+def _pack(x, words: int):
+    """(B, n) bool → (W, n) uint32: lane ``32w + j`` is bit ``j`` of word
+    ``w``; lanes past B stay 0."""
+    b, n = x.shape
+    x = jnp.pad(jnp.asarray(x, bool), ((0, 32 * words - b), (0, 0)))
+    bits = x.reshape(words, 32, n).astype(jnp.uint32) \
+        << jnp.arange(32, dtype=jnp.uint32)[None, :, None]
+    return jnp.sum(bits, axis=1, dtype=jnp.uint32)
+
+
+def _unpack(words, b: int):
+    """(W, n) uint32 → (B, n) bool."""
+    bits = words[:, None, :] >> jnp.arange(32, dtype=jnp.uint32)[None, :,
+                                                                   None]
+    return (bits & 1).astype(bool).reshape(-1, words.shape[1])[:b]
+
+
+def _live_lanes(words, b: int):
+    """(B,) bool: lane has a bit set at some vertex."""
+    agg = jax.lax.reduce(words, jnp.uint32(0), jax.lax.bitwise_or, (1,))
+    return _unpack(agg[:, None], b)[:, 0]
+
+
+def _take_words(words, idx):
+    """``words[:, idx]``, 0 past the end: one gather of W-word columns.
+    On a v5e it costs per index, not per byte: at W = 2 and 2^25 indices
+    it takes 205 ms, and W gathers of single words 290 ms each."""
+    return jnp.take(words, idx, axis=1, mode="fill", fill_value=0)
+
+
+def _pull_round(view: PullView, words):
+    """``E·Δ`` for packed 𝔹 lanes: (W, n) → (W, n) uint32.  Its three
+    parts carry scopes of their own, for the trace's split."""
+    with jax.named_scope("gather"):
+        g = _take_words(words, view.src)                      # (W, cap)
+    # segmented OR by doubling: after pass k each position holds the OR
+    # of the last 2^(k+1) positions of its run
+    with jax.named_scope("segment_or"):
+        for k in range(view.steps):
+            sh = 1 << k
+            prev = jnp.pad(g[:, :-sh], ((0, 0), (sh, 0)))
+            g = g | jnp.where(view.off >= sh, prev, jnp.uint32(0))
+    with jax.named_scope("ends"):
+        return _take_words(g, view.last)
+
+
+def _packed_chunk_loop(view: PullView, y0, d0, it0, max_iters):
+    """The chunk body on packed words: ``y ∪ Δ`` is ``|``, ``E·Δ ⊖ y``
+    is ``& ~``.  Same rounds, masks and counts as :func:`_chunk_loop`;
+    packs at entry and unpacks at exit, so the carry is (B, n) bool
+    either side."""
+    b = jnp.shape(y0)[0]
+    w = -(-b // 32)
+    y, d = _pack(y0, w), _pack(d0, w)
+    it_rows = jnp.asarray(it0, jnp.int32)
+
+    def cond(carry):
+        y, d, it_rows, it = carry
+        return jnp.logical_and(jnp.any(d != 0), it < max_iters)
+
+    def body(carry):
+        y, d, it_rows, it = carry
+        live = _live_lanes(d, b)
+        y_new = y | d
+        with jax.named_scope("advance"):
+            e_d = _pull_round(view, d)
+        return y_new, e_d & ~y_new, it_rows + live, it + 1
+
+    y, d, it_rows, _ = jax.lax.while_loop(
+        cond, body, (y, d, it_rows, jnp.asarray(0)))
+    return _unpack(y, b), _unpack(d, b), it_rows
+
+
+class CompiledChunk:
+    """The staged loop's compiled chunk ``(e, y, d, it) → (y, d, it)``
+    over a (B, n) carry, for the serve pools and ``sparse_jit``'s
+    ``run_chunk``.  The operator's :class:`PullView` is looked up
+    outside the jit and passed in as an argument; the jitted program is
+    named ``fixpoint_chunk``, so a device trace calls it
+    ``jit_fixpoint_chunk``."""
+
+    def __init__(self, max_iters: int):
+        def fixpoint_chunk(e, view, y, d, it):
+            return _resume_chunk(e, y, d, it, max_iters=max_iters,
+                                 view=view)
+        self._jit = jax.jit(fixpoint_chunk)
+
+    @staticmethod
+    def packs(edges: SparseRelation) -> bool:
+        """Whether this chunk's rounds take the packed pull round."""
+        return takes_pull_round(edges.semiring)
+
+    def __call__(self, e, y, d, it):
+        view = pull_view(e) if self.packs(e) else None
+        return self._jit(e, view, y, d, it)
 
 
 # --------------------------------------------------------------------------

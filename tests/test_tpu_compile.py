@@ -7,6 +7,8 @@ casts it has no rule for — so these tests guard the three kernels the
 serve path dispatches to on TPU, at the shapes ``chip_smoke.py`` runs
 them (and the segment-⊕ at the 1M-vertex serving size).  Each asserts
 that the compiled program holds the Mosaic kernel (``tpu_custom_call``).
+The 𝔹 serve chunk, which is plain XLA, is compiled here too, and checked
+for the scatter and sort it no longer needs.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and the suite's
@@ -107,3 +109,25 @@ def test_semiring_matmul_compiles(one_chip, sr_name, m, k, n):
                                                     sr_name=sr_name)),
         _shape(one_chip, (m, k), DTYPE[sr_name]),
         _shape(one_chip, (k, n), DTYPE[sr_name]))
+
+
+@pytest.mark.parametrize("b", [64, 33])
+def test_packed_chunk_compiles(one_chip, b):
+    """The serve pools' 𝔹 chunk (packed pull round) at the cell's lane
+    widths on a 2^16-vertex, 2^21-slot operator: its program holds a
+    gather and neither a scatter nor a sort."""
+    from repro.sparse import fixpoint as fx
+    n, cap = 1 << 16, 1 << 21
+    e = SparseRelation(_shape(one_chip, (cap, 2), jnp.int32),
+                       _shape(one_chip, (cap,), jnp.bool_),
+                       _shape(one_chip, (), jnp.int32), (n, n), "bool")
+    view = fx.PullView(_shape(one_chip, (cap,), jnp.int32),
+                       _shape(one_chip, (cap,), jnp.int32),
+                       _shape(one_chip, (n,), jnp.int32), 17)
+    carry = (_shape(one_chip, (b, n), jnp.bool_),
+             _shape(one_chip, (b, n), jnp.bool_),
+             _shape(one_chip, (b,), jnp.int32))
+    text = fx.CompiledChunk(4)._jit.lower(e, view, *carry).compile() \
+        .as_text()
+    assert "gather(" in text
+    assert "scatter(" not in text and " sort(" not in text
