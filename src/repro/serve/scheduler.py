@@ -35,7 +35,8 @@ vLLM-style loop the per-row convergence masks were built for:
 * **metrics**: queue/compute/total latency of every request stream into
   the streaming histograms of :mod:`repro.serve.metrics`; ``stats()``
   exposes p50/p95/p99 plus counter totals (``rounds``,
-  ``packed_rounds`` and ``carry_bytes`` among them) and per-family
+  ``packed_rounds``, ``carry_bytes``, ``rows_in`` and ``rows_out``
+  among them) and per-family
   gauges (``frontier_nnz`` read on demand); the stages of a round are
   :mod:`repro.trace` spans.
 
@@ -116,6 +117,7 @@ class ContinuousServer:
             "admitted": 0, "evicted": 0, "chunks": 0, "migrated": 0,
             "latency_routed": 0, "packed_fallback": 0,
             "rounds": 0, "packed_rounds": 0, "carry_bytes": 0,
+            "rows_in": 0, "rows_out": 0,
         }
 
     # -- registration -------------------------------------------------------
@@ -193,13 +195,11 @@ class ContinuousServer:
                 self._admit(fs, delivered)
                 if fs.pool is None or fs.pool.occupied == 0:
                     break
-                rounds, packed, moved = fs.pool.step(self.chunk_iters)
+                inc = fs.pool.step(self.chunk_iters)
                 self._counters["chunks"] += 1
-                self._counters["rounds"] += rounds
-                self._counters["packed_rounds"] += packed
-                self._counters["carry_bytes"] += moved
-                counters(rounds=rounds, packed_rounds=packed,
-                         carry_bytes=moved)
+                for key, v in inc.items():
+                    self._counters[key] += v
+                counters(**inc)
                 self._harvest(fs, delivered)
         return delivered
 

@@ -19,7 +19,10 @@ Three interchangeable chunk steppers implement the same GSN body:
   ``resume_fixpoint_chunk`` (one SpMM per round, chunked
   ``lax.while_loop``; for 𝔹 the packed pull round), compiled once per
   ``(plan.signature, B-bucket, D)`` exactly like the packed server's
-  runners.
+  runners.  Its carry lives on the device for the pool's whole life:
+  admissions are spliced in by one small program per chunk, and only
+  the admitted rows, the per-lane live mask and rounds, and the rows of
+  converged lanes cross the host link (a 𝔹 row bit-packed).
 * :class:`BitsetBoolStepper` — boolean semiring on CPU: the B query
   lanes live as bits of ``⌈B/64⌉`` uint64 words per vertex, and a round
   is the fused kernel's packed-𝔹 advance
@@ -50,8 +53,10 @@ informational either way.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import semiring as sr_mod
@@ -100,7 +105,7 @@ class BitsetBoolStepper:
     """
 
     #: the carry never leaves the host
-    carry_bytes = 0
+    carry_bytes = rows_in = rows_out = 0
     #: every round is a bit-packed pull over dst-sorted edges
     packed = True
 
@@ -166,7 +171,7 @@ class LevelSyncTropStepper:
     falls back to the jax stepper.
     """
 
-    carry_bytes = 0
+    carry_bytes = rows_in = rows_out = 0
     packed = False
 
     def __init__(self, edges: SparseRelation, n: int, b: int,
@@ -269,11 +274,60 @@ class LevelSyncTropStepper:
         self.dist[j] = _INF32
 
 
+@functools.partial(jax.jit, static_argnames=("zero",),
+                   donate_argnums=(0, 1, 2))
+def _splice(y, d, it, idx, rows, *, zero):
+    """Splice staged rows into the carry in place: ``y_row ← 0̄``,
+    ``Δ_row ← rows[i]``, ``it_row ← 0`` for each lane ``idx[i]``.
+    ``idx`` is padded to B with out-of-range lanes, which are dropped;
+    ``rows`` holds B row buffers (bit-packed uint8 for a bool carry).
+    Row selects and ``where``s over the carry, no scatter into it."""
+    b = it.shape[0]
+    lane = jnp.full(b, b, jnp.int32).at[idx].set(
+        jnp.arange(b, dtype=jnp.int32), mode="drop")
+    new = (lane < b)[:, None]
+    rows = jnp.stack(rows)[jnp.minimum(lane, b - 1)]
+    if d.dtype == jnp.bool_:
+        rows = jnp.unpackbits(rows, axis=1, count=d.shape[1]).astype(bool)
+    y = jnp.where(new, jnp.asarray(zero, y.dtype), y)
+    d = jnp.where(new, rows.astype(d.dtype), d)
+    it = jnp.where(new[:, 0], 0, it)
+    return y, d, it
+
+
+@functools.partial(jax.jit, static_argnames=("zero",))
+def _live(d, *, zero):
+    """Per-lane liveness: any entry of the lane's Δ row off 0̄."""
+    return jnp.any(d != jnp.asarray(zero, d.dtype), axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("zero",))
+def _nnz(d, *, zero):
+    return jnp.sum(d != jnp.asarray(zero, d.dtype), dtype=jnp.int32)
+
+
+@jax.jit
+def _gather(y, idx):
+    """The rows of ``idx`` (padded to B), a bool carry's bit-packed, as
+    B separate buffers, so the host copies only the rows it asks for."""
+    rows = y[idx]
+    if rows.dtype == jnp.bool_:
+        rows = jnp.packbits(rows, axis=1)
+    return tuple(rows[i] for i in range(rows.shape[0]))
+
+
 class JaxChunkStepper:
-    """The general chunk stepper: host-resident (B, n) carry advanced by
-    a jitted bounded slice of the batched GSN loop.  ``packed`` says
-    whether the chunk's rounds take the packed pull round, as a
-    :class:`~repro.sparse.fixpoint.CompiledChunk` reports it."""
+    """The general chunk stepper: a device-resident (B, n) carry
+    advanced by a jitted bounded slice of the batched GSN loop.
+
+    ``y``, ``d`` and ``it`` stay on the device for the pool's life; only
+    rows and the live mask cross the host link.  ``admit`` stages a row
+    on the host and the next chunk splices every staged row in one call;
+    after each chunk one reduction over Δ brings back the live mask, and
+    the rows of lanes that converged are copied out (a bool row
+    bit-packed).  ``packed`` says whether the chunk's rounds take the
+    packed pull round, as a :class:`~repro.sparse.fixpoint.CompiledChunk`
+    reports it."""
 
     def __init__(self, edges: SparseRelation, n: int, b: int,
                  chunk_fn):
@@ -284,35 +338,90 @@ class JaxChunkStepper:
         self.packed = packs is not None and packs(edges)
         sr = sr_mod.get(edges.semiring, lib="np")
         self._sr = sr
-        self.y = np.full((b, n), sr.zero, sr.dtype)
-        self.d = np.full((b, n), sr.zero, sr.dtype)
+        self._zero = np.asarray(sr.zero, sr.dtype).item()
+        self._bool = np.dtype(sr.dtype) == np.bool_
+        self.y = jnp.full((b, n), sr.zero, sr.dtype)
+        self.d = jnp.full((b, n), sr.zero, sr.dtype)
+        self.it_dev = jnp.zeros(b, jnp.int32)
+        # host mirrors: per-lane liveness and rounds, as of the last chunk
+        # or admission
+        self.live = np.zeros(b, bool)
         self.it = np.zeros(b, np.int32)
+        self._occupied = np.zeros(b, bool)
+        self._staged: dict[int, np.ndarray] = {}
+        self._out: dict[int, np.ndarray] = {}
+        width = -(-n // 8) if self._bool else n
+        self._pad = jnp.zeros(width, np.uint8 if self._bool else sr.dtype)
         self.carry_bytes = 0
+        self.rows_in = 0
+        self.rows_out = 0
 
     def admit(self, j: int, init: np.ndarray) -> bool:
-        zero_row = np.full(self.n, self._sr.zero, self._sr.dtype)
-        self.y[j] = zero_row
         # the cold GSN seed: d0 = (init ⊕ 0̄⊗E) ⊖ 0̄ = init ⊖ 0̄
-        self.d[j] = self._sr.minus(np.asarray(init, self._sr.dtype),
-                                   zero_row)
+        zero_row = np.full(self.n, self._sr.zero, self._sr.dtype)
+        d = self._sr.minus(np.asarray(init, self._sr.dtype), zero_row)
+        self.live[j] = bool((d != zero_row).any())
+        self._staged[j] = np.packbits(d) if self._bool else d
+        self._occupied[j] = True
         self.it[j] = 0
         return True
 
     def live_lanes(self) -> np.ndarray:
-        return np.asarray(
-            (self.d != np.asarray(self._sr.zero,
-                                  self._sr.dtype)).any(axis=1))
+        return self.live.copy()
 
     def frontier_nnz(self) -> int:
-        return int((self.d != np.asarray(self._sr.zero,
-                                         self._sr.dtype)).sum())
+        """Live Δ entries, counted on the device on demand; a staged row
+        replaces a converged lane's 0̄ row, so it adds its own count."""
+        staged = sum(int(np.unpackbits(r).sum()) if self._bool
+                     else int((r != self._zero).sum())
+                     for r in self._staged.values())
+        return int(_nnz(self.d, zero=self._zero)) + staged
+
+    def _upload(self) -> int:
+        """Splice every staged row in one call; the bytes sent up."""
+        if not self._staged:
+            return 0
+        k = len(self._staged)
+        idx = np.full(self.b, self.b, np.int32)
+        idx[:k] = list(self._staged)
+        staged = list(self._staged.values())
+        moved = idx.nbytes + sum(r.nbytes for r in staged)
+        # every row a device buffer, the pad rows resident: one program
+        # for every admission count
+        rows = tuple(jax.device_put(staged)) + (self._pad,) * (self.b - k)
+        self.rows_in += k
+        self._staged.clear()
+        # blocked, so the upload span covers the splice and pool.run
+        # only the chunk
+        self.y, self.d, self.it_dev = jax.block_until_ready(_splice(
+            self.y, self.d, self.it_dev, idx, rows, zero=self._zero))
+        return moved
+
+    def _download(self) -> int:
+        """Bring back the live mask and the rounds, then copy out the
+        rows of occupied lanes that converged; the bytes moved."""
+        live, it = jax.device_get((_live(self.d, zero=self._zero),
+                                   self.it_dev))
+        self.live, self.it = np.array(live), np.array(it, np.int32)
+        moved = self.live.nbytes + self.it.nbytes
+        done = [int(j) for j in np.flatnonzero(self._occupied & ~self.live)
+                if j not in self._out]
+        if done:
+            idx = np.zeros(self.b, np.int32)
+            idx[:len(done)] = done
+            rows = jax.device_get(_gather(self.y, idx)[:len(done)])
+            self._out.update(zip(done, rows))
+            self.rows_out += len(done)
+            moved += idx.nbytes + sum(r.nbytes for r in rows)
+        return moved
 
     def step(self, k: int) -> None:
         """One chunk.  ``carry_bytes`` counts the bytes it moved between
-        host and device: every argument not already on the device, and
-        the three outputs copied back."""
+        host and device: edges not already on the device, the staged
+        rows and their index, the live mask and rounds, and the copied
+        out rows and their index."""
         with span("pool.scan"):
-            if not self.live_lanes().any():
+            if not self.live.any():
                 return
         with span("pool.upload"):
             edges = self.edges
@@ -320,27 +429,32 @@ class JaxChunkStepper:
                         for x in (edges.coords, edges.values, edges.nnz)
                         if not isinstance(x, jax.Array))
             edges = edges.as_jnp()
-            carry = (self.y, self.d, self.it)
-            moved += sum(x.nbytes for x in carry)
-            y, d, it = jax.block_until_ready(jax.device_put(carry))
+            moved += self._upload()
         with span("pool.run"):
-            y, d, it = jax.block_until_ready(self._chunk(edges, y, d, it))
+            self.y, self.d, self.it_dev = jax.block_until_ready(
+                self._chunk(edges, self.y, self.d, self.it_dev))
         with span("pool.download"):
-            # np.array, not asarray: jax hands back read-only zero-copy
-            # views on CPU, and admit/release scribble rows in place
-            self.y = np.array(y)
-            self.d = np.array(d)
-            self.it = np.array(it, np.int32)
-        self.carry_bytes += (moved + self.y.nbytes + self.d.nbytes
-                             + self.it.nbytes)
+            moved += self._download()
+        self.carry_bytes += moved
 
     def extract(self, j: int) -> tuple[np.ndarray, int]:
-        return self.y[j].copy(), int(self.it[j])
+        """Lane ``j``'s answer as a writable host row.  A staged lane's
+        is 0̄ (its splice is pending); a converged lane's was copied out
+        by the chunk that saw it converge."""
+        if j in self._staged:
+            return np.full(self.n, self._sr.zero, self._sr.dtype), 0
+        row = self._out[j]
+        if self._bool:
+            return np.unpackbits(row, count=self.n).astype(bool), \
+                int(self.it[j])
+        return np.array(row), int(self.it[j])
 
     def release(self, j: int) -> None:
-        zero_row = np.full(self.n, self._sr.zero, self._sr.dtype)
-        self.y[j] = zero_row
-        self.d[j] = zero_row
+        """Host bookkeeping only: a converged lane's Δ row is already 0̄,
+        and the next admission overwrites its y row."""
+        self._occupied[j] = False
+        self._staged.pop(j, None)
+        self._out.pop(j, None)
 
 
 def build_stepper(fam: Family, b: int, *, host_kernels: bool,
@@ -404,21 +518,26 @@ class SlotPool:
         self.slots[j] = req
         return True
 
-    def step(self, k: int) -> tuple[int, int, int]:
-        """Step one chunk of at most ``k`` rounds; returns the rounds
-        the device ran, those of them that took the packed pull round,
-        and the carry bytes moved between host and device.  Rounds are
-        the most any lane advanced: a lane whose Δ row is 0̄ stays 0̄, so
-        the lane live in the last round was live in every round."""
+    def step(self, k: int) -> dict[str, int]:
+        """Step one chunk of at most ``k`` rounds; returns its counter
+        increments: the rounds the device ran, those of them that took
+        the packed pull round, the carry bytes moved between host and
+        device, and the rows spliced in and copied out.  Rounds are the
+        most any lane advanced: a lane whose Δ row is 0̄ stays 0̄, so the
+        lane live in the last round was live in every round."""
         st = self.stepper
-        it, moved = st.it.copy(), st.carry_bytes
+        before = (st.it.copy(), st.carry_bytes, st.rows_in, st.rows_out)
         st.step(k)
-        rounds = int((st.it - it).max(initial=0))
-        return rounds, rounds if st.packed else 0, st.carry_bytes - moved
+        rounds = int((st.it - before[0]).max(initial=0))
+        return {"rounds": rounds,
+                "packed_rounds": rounds if st.packed else 0,
+                "carry_bytes": st.carry_bytes - before[1],
+                "rows_in": st.rows_in - before[2],
+                "rows_out": st.rows_out - before[3]}
 
     def frontier_nnz(self) -> int:
-        """Live Δ entries across all lanes: one scan of the carry, on
-        demand (``stats()["families"][f]["frontier_nnz"]``)."""
+        """Live Δ entries across all lanes, on demand
+        (``stats()["families"][f]["frontier_nnz"]``)."""
         return self.stepper.frontier_nnz()
 
     def harvest(self) -> list[tuple[QueryRequest, np.ndarray, int]]:

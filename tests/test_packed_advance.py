@@ -252,14 +252,18 @@ def test_pull_view_built_once_per_operator(monkeypatch):
     cs = _server("bool", n=256, max_batch=8)
     fam_edges = cs._families["f"].fam.edges
     fx._PULL_CACHE.clear()
-    for s in (3, 7):                      # two: one would skip the pool
-        cs.submit("f", s)
+    reqs = [cs.submit("f", s) for s in (3, 7)]  # one would skip the pool
     cs.run_until_idle()
     first = cs.stats()
     assert first["chunks"] > 1 and len(builds) == 1
     b = first["families"]["f"]["pool_b"]
-    assert first["carry_bytes"] == first["chunks"] * (2 * 2 * b * 256
-                                                      + 2 * 4 * b)
+    # per chunk the live mask and the rounds (5 bytes a lane); one splice
+    # of both packed rows (256 bits each) with its index; a copy-out with
+    # its index in each chunk that saw a lane converge
+    outs = len({-(-r.iters // cs.chunk_iters) for r in reqs})
+    assert first["rows_in"] == first["rows_out"] == 2
+    assert first["carry_bytes"] == (first["chunks"] * 5 * b + 4 * b
+                                    + outs * 4 * b + 4 * 256 // 8)
     for s in range(8):                    # a wider pool on the same edges
         cs.submit("f", 10 + s)
     cs.run_until_idle()

@@ -138,6 +138,53 @@ def test_continuous_jax_chunk_stepper_exact():
     assert cs.stats()["compile_cache"]["misses"] >= 1
 
 
+@pytest.mark.parametrize("semiring", ["bool", "trop"])
+def test_device_pool_exact_through_migration_and_fence(semiring):
+    """The device-resident pool (host_kernels=False) answers every query
+    bit for bit as the single-source engine, rounds included, through
+    admission, harvest, migration to a larger bucket and the update
+    fence; ``rows_in`` counts every row spliced (a migrated row twice)
+    and ``rows_out`` every row copied out."""
+    from repro.serve import family as fam_mod
+    from repro.sparse import fixpoint as fx
+
+    cs = ContinuousServer(max_batch=8, chunk_iters=2, warm_answers=0,
+                          host_kernels=False)
+    if semiring == "bool":
+        g, db = _bm_db()
+        cs.register("f", lambda a: programs.bm(a=a).optimized, db)
+        update = ([[0, g.n - 1]], None)
+    else:
+        g, db, mk = _sssp_setup()
+        cs.register("f", mk, db, edges=g.sparse_adjacency(semiring="trop"))
+        update = ([[0, g.n - 1]], [1.0])
+    fs = cs._families["f"]
+    before = [cs.submit("f", s) for s in (1, 5)]
+    cs.step()                        # a B=2 pool, its rows on the device
+    before += [cs.submit("f", s) for s in range(10, 16)]
+    cs.step()                        # demand of 8: migrate to B=8
+    assert cs.stats()["migrated"] >= 1
+    assert cs.stats()["families"]["f"]["pool_b"] == 8
+    before += [cs.submit("f", s) for s in range(30, 90, 5)]  # reuse lanes
+    edges_before = fs.fam.edges
+    cs.submit_update("f", *update)
+    after = [cs.submit("f", s) for s in (0, 20, 40)]
+    cs.run_until_idle()
+    assert fs.fam.edges is not edges_before
+    for reqs, edges in ((before, edges_before), (after, fs.fam.edges)):
+        for r in reqs:
+            y, iters = fx.fixpoint(edges, fam_mod.family_init(fs.fam,
+                                                               r.source))
+            assert np.array_equal(np.asarray(r.result), np.asarray(y)), \
+                r.source
+            assert r.iters == int(iters), r.source
+    st = cs.stats()
+    assert st["updates"] == 1 and st["latency_routed"] == 0
+    assert st["admitted"] == st["evicted"] == len(before) + len(after)
+    assert st["rows_in"] == st["admitted"] + st["migrated"]
+    assert st["rows_out"] == st["evicted"]
+
+
 def test_continuous_dense_packed_fallback():
     """A dense-operator family has no columnwise splice: the scheduler
     serves it through the packed whole-run fallback, still exactly."""

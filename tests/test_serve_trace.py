@@ -12,8 +12,9 @@ import jax.numpy as jnp
 
 from repro import trace
 from repro.core import engine
+from repro.core import semiring as sr_mod
 from repro.datalog import datasets, programs
-from repro.serve import ContinuousServer
+from repro.serve import ContinuousServer, slots
 
 N = 12
 SOURCES = (2, 5, 9)
@@ -60,8 +61,16 @@ def test_chunk_spans_rounds_and_carry_bytes(semiring, itemsize):
     chunks = stats["chunks"]
     assert chunks == -(-(N - min(SOURCES)) // CHUNK)
     b = stats["families"]["f"]["pool_b"]
-    assert stats["carry_bytes"] == chunks * (2 * 2 * b * N * itemsize
-                                             + 2 * 4 * b)
+    # the carry stays on the device: each chunk brings back the live
+    # mask (1 byte a lane) and the rounds (4); the three rows go up in
+    # one splice with its index (4 bytes a lane), and each comes back in
+    # the chunk that saw it converge (three chunks), with its index; a
+    # 𝔹 row crosses bit-packed
+    row = -(-N // 8) if semiring == "bool" else N * itemsize
+    assert stats["rows_in"] == stats["rows_out"] == len(SOURCES)
+    assert stats["carry_bytes"] == (chunks * 5 * b + 4 * b
+                                    + len(SOURCES) * row
+                                    + len(SOURCES) * (4 * b + row))
     stages = [s for s in rec if s[0].startswith(("serve.", "pool."))]
     assert [n for n, _, _ in stages] == list(STAGES) * chunks
     ends = [t for _, t0, t1 in stages for t in (t0, t1)]
@@ -93,7 +102,7 @@ def test_counters_and_spans_reach_the_profile(semiring, tmp_path):
     marks = [dict(e.stats) for e in events if e.name == "counters"]
     stats = cs.stats()
     assert len(marks) == stats["chunks"] > 0
-    for key in ("rounds", "carry_bytes"):
+    for key in ("rounds", "carry_bytes", "rows_in", "rows_out"):
         assert sum(m[key] for m in marks) == stats[key] > 0
     assert set(STAGES) <= {e.name for e in events}
 
@@ -115,6 +124,64 @@ def test_frontier_nnz_read_on_demand(semiring):
 
 
 @pytest.mark.parametrize("semiring", ["bool", "trop"])
+def test_pool_programs_compile_once_across_admission_counts(semiring):
+    """Splice, live mask and copy-out each compile once for a pool's
+    shape, whether a chunk admits or copies out 1 row or all B; every
+    row comes out as the single-source run gives it."""
+    from repro.sparse import fixpoint as fx
+
+    n, b = 2 * N + 1, 4              # a shape no other test compiles
+    coords = np.stack([np.arange(n - 1), np.arange(1, n)], axis=1)
+    rel = datasets.Graph(n, coords).sparse_adjacency(semiring=semiring)
+    st = slots.JaxChunkStepper(rel, n, b, fx.CompiledChunk(CHUNK))
+    progs = (slots._splice, slots._live, slots._gather)
+    sizes = [p._cache_size() for p in progs]
+    sr = sr_mod.get(semiring, lib="np")
+    for k in range(1, b + 1):
+        inits = {}
+        for j in range(k):
+            inits[j] = np.full(n, sr.zero, sr.dtype)
+            inits[j][3 * j + k] = sr.one
+            st.admit(j, inits[j])
+        while st.live_lanes()[:k].any():
+            st.step(CHUNK)
+        for j, init in inits.items():
+            y, iters = st.extract(j)
+            st.release(j)
+            want, want_iters = fx.fixpoint(rel, init)
+            assert np.array_equal(y, np.asarray(want)) and y.flags.writeable
+            assert iters == int(want_iters)
+    assert [p._cache_size() for p in progs] == [m + 1 for m in sizes]
+    assert st.rows_in == st.rows_out == b * (b + 1) // 2
+
+
+@pytest.mark.parametrize("semiring", ["bool", "trop"])
+def test_no_full_carry_reaches_the_host(semiring, monkeypatch):
+    """The (B, n) carry stays on the device: what a chunk brings back is
+    the live mask, the rounds and converged rows, never a (B, n) array."""
+    from jax._src import array as jax_array
+
+    fetched = []
+    value = jax_array.ArrayImpl._value
+
+    def counting(self):
+        fetched.append(self.shape)
+        return value.fget(self)
+
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(counting))
+    cs = ContinuousServer(max_batch=4, chunk_iters=CHUNK, warm_answers=0,
+                          host_kernels=False)
+    _register(cs, semiring)
+    reqs = _serve(cs)
+    assert [r.iters for r in reqs] == [N - s for s in SOURCES]
+    b = cs.stats()["families"]["f"]["pool_b"]
+    assert fetched and all(np.prod(s) < b * N for s in fetched), fetched
+    stepper = cs._families["f"].pool.stepper
+    assert isinstance(stepper.y, jax.Array)
+    assert isinstance(stepper.d, jax.Array)
+
+
+@pytest.mark.parametrize("semiring", ["bool", "trop"])
 def test_host_stepper_counts_rounds_and_no_bytes(semiring):
     cs = ContinuousServer(max_batch=4, chunk_iters=CHUNK, warm_answers=0,
                           host_kernels=True)
@@ -123,6 +190,6 @@ def test_host_stepper_counts_rounds_and_no_bytes(semiring):
         reqs = _serve(cs)
     stats = cs.stats()
     assert stats["rounds"] == max(r.iters for r in reqs) > 0
-    assert stats["carry_bytes"] == 0
+    assert stats["carry_bytes"] == stats["rows_in"] == stats["rows_out"] == 0
     assert {n for n, _, _ in rec} >= {"serve.admit", "serve.harvest"}
     assert not any(n.startswith("pool.") for n, _, _ in rec)

@@ -131,3 +131,29 @@ def test_packed_chunk_compiles(one_chip, b):
         .as_text()
     assert "gather(" in text
     assert "scatter(" not in text and " sort(" not in text
+
+
+@pytest.mark.parametrize("sr_name", ["bool", "trop"])
+def test_pool_carry_programs_compile(one_chip, sr_name):
+    """The slot pool's own programs at the cell's size (B=64 lanes of
+    2^20 vertices): the splice updates the carry in place (its y, Δ and
+    rounds alias their inputs), and the live mask and the copy-out
+    compile beside it."""
+    from repro.core import semiring as sr_mod
+    from repro.serve import slots
+    b, n = 64, 1 << 20
+    dtype = DTYPE[sr_name]
+    zero = np.asarray(sr_mod.get(sr_name, lib="np").zero, dtype).item()
+    row = (_shape(one_chip, (n // 8,), jnp.uint8) if sr_name == "bool"
+           else _shape(one_chip, (n,), dtype))
+    carry = (_shape(one_chip, (b, n), dtype),
+             _shape(one_chip, (b, n), dtype),
+             _shape(one_chip, (b,), jnp.int32))
+    idx = _shape(one_chip, (b,), jnp.int32)
+    splice = slots._splice.lower(*carry, idx, (row,) * b,
+                                 zero=zero).compile()
+    aliases = splice.as_text().split("input_output_alias={")[1]
+    assert all(f"{{{k}}}: ({k}, {{}}" in aliases for k in range(3))
+    slots._live.lower(carry[1], zero=zero).compile()
+    gather = slots._gather.lower(carry[0], idx).compile()
+    assert len(gather.out_info) == b
